@@ -19,6 +19,8 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args = [a for a in sys.argv[1:] if a != "--quick"]
     quick = "--quick" in sys.argv[1:]
     if quick:

@@ -41,7 +41,7 @@ ENGINE_PATH = "src/repro/serving/engine.py"
 _CALLBACK_TAGS = ("callback", "infeed", "outfeed")
 _TRANSFER_PRIMS = {"device_put"}
 
-_COMPILE_RE = re.compile(r"Compiling ([\w.<>\[\]-]+) with global shapes")
+_COMPILE_RE = re.compile(r"Compiling jit\(([\w.<>\[\]-]+)\) with global shapes")
 
 # RL104 only looks at inputs at least this large — below it a defensive copy
 # is noise, not a throughput bug
@@ -144,7 +144,7 @@ class CompileLog:
 
 # ------------------------------------------------------------ per-stage checks
 def _iter_subjaxprs(params: Dict[str, Any]):
-    import jax.core as jcore
+    import jax.extend.core as jcore
     for v in params.values():
         vals = v if isinstance(v, (list, tuple)) else (v,)
         for x in vals:

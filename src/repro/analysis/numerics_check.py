@@ -121,7 +121,7 @@ def _site(eqn, default_path: str) -> Tuple[str, int]:
     """Repo-relative (path, line) of the user frame that traced ``eqn``."""
     try:
         from jax._src import source_info_util as siu
-        fr = siu.user_frame(eqn.source_info)
+        fr = siu.user_frame(eqn.source_info.traceback)
         if fr is not None:
             path = fr.file_name.replace("\\", "/")
             i = path.rfind("/src/repro/")
@@ -170,7 +170,7 @@ class _Graph:
 
 def _subjaxprs(params):
     """Every Jaxpr reachable from an eqn's params (mirrors jaxpr_check)."""
-    import jax.core as jc
+    import jax.extend.core as jc
     for v in params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for x in vs:
@@ -193,7 +193,7 @@ def _build_units(closed, name: str) -> List[_Graph]:
     """Flatten a ClosedJaxpr into analysis units: the top-level graph (with
     all call-like prims inlined) plus one unit per control-flow/kernel body,
     recursively. Pallas kernel bodies are marked ``in_kernel``."""
-    import jax.core as jc
+    import jax.extend.core as jc
     units: List[_Graph] = []
     pending: List[Tuple[Any, str, bool]] = [(closed.jaxpr, name, False)]
     while pending:

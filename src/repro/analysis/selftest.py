@@ -496,10 +496,6 @@ def _selftest_rl405() -> List[str]:
     if fs:
         fails.append(f"RL405: f32 parts falsely flagged: {fs[0].render()}")
     # collective flavor: a psum over bf16 partials inside shard_map
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:                                    # pragma: no cover
-        from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
 
@@ -507,8 +503,8 @@ def _selftest_rl405() -> List[str]:
         def body(x):
             y = x.astype(jnp.bfloat16) if cast else x
             return jax.lax.psum(y, "x")
-        return shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                         check_rep=False)
+        return jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)
     aval = (jax.ShapeDtypeStruct((8,), f32),)
     fails += _num_check(collective(True), aval,
                         "RL405", True, "psum over bf16 partials")

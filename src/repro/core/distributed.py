@@ -30,18 +30,6 @@ from repro.core.wave_index import WaveState
 from repro.core.zones import ZonePlan
 
 
-def _shard_map(body, mesh, in_specs, out_specs, axis_names):
-    """Version shim: jax >= 0.6 exposes jax.shard_map (axis_names/check_vma);
-    earlier releases ship jax.experimental.shard_map (check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def local_plan(plan: ZonePlan, n_shards: int) -> ZonePlan:
     return plan._replace(r=max(1, math.ceil(plan.r / n_shards)),
                          e=max(1, math.ceil(plan.e / n_shards)))
@@ -59,9 +47,7 @@ def shard_wave_attention(q, state: WaveState, retro: RetroConfig,
     PartitionId op that SPMD can't partition when other mesh axes stay auto.
     """
     B, Hq, hd = q.shape
-    # jax >= 0.6 has lax.axis_size; older releases statically fold psum(1, ax)
-    n_sh = jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size") \
-        else jax.lax.psum(1, axis)
+    n_sh = jax.lax.axis_size(axis)
     ax = shard_id[0] if shard_id is not None else jax.lax.axis_index(axis)
     m_loc = state.centroid.shape[2]
     lp = local_plan(plan, n_sh)
@@ -105,6 +91,9 @@ def distributed_wave_attention(q, state: WaveState, retro: RetroConfig,
     ``window`` may be a traced scalar — passed as an explicit (replicated)
     shard_map operand rather than captured in the closure."""
     manual = frozenset({axis})
+    # the body leaves every axis but ``axis`` to the partitioner, which needs
+    # Auto axis types; jax.make_mesh builds Explicit ones by default
+    mesh = jax.sharding.Mesh(mesh.devices, mesh.axis_names)
     state_specs = state_specs_cluster_sharded(state, axis)
     n_sh = mesh.shape[axis]
     shard_ids = jnp.arange(n_sh, dtype=jnp.int32)
@@ -114,13 +103,15 @@ def distributed_wave_attention(q, state: WaveState, retro: RetroConfig,
             return shard_wave_attention(q, s, retro, plan, axis=axis,
                                         window=w, softcap=softcap,
                                         shard_id=sid)
-        fn = _shard_map(body, mesh, (P(), state_specs, P(axis), P()),
-                        P(), manual)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(), state_specs, P(axis), P()),
+                           out_specs=P(), axis_names=manual, check_vma=False)
         return fn(q, state, shard_ids, jnp.asarray(window, jnp.float32))
 
     def body(q, s, sid):
         return shard_wave_attention(q, s, retro, plan, axis=axis,
                                     window=None, softcap=softcap,
                                     shard_id=sid)
-    fn = _shard_map(body, mesh, (P(), state_specs, P(axis)), P(), manual)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), state_specs, P(axis)),
+                       out_specs=P(), axis_names=manual, check_vma=False)
     return fn(q, state, shard_ids)

@@ -53,8 +53,8 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, est_logit_ref, cs_ref, vs_ref,
                             preferred_element_type=jnp.float32) * scale
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    ok = valid_ref[0] > 0                           # (Tb,)
-    s = jnp.where(ok[None, :], s, NEG)              # (G, Tb)
+    ok = valid_ref[0] > 0                           # (1, Tb)
+    s = jnp.where(ok, s, NEG)                       # (G, Tb)
 
     m_prev = m_scr[...]                             # (G, 1) layout -> (G,)
     m_new = jnp.maximum(m_prev[:, 0], jnp.max(s, axis=-1))
@@ -62,7 +62,7 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, est_logit_ref, cs_ref, vs_ref,
     corr = jnp.where(jnp.isfinite(m_prev[:, 0]),
                      jnp.exp(m_prev[:, 0] - m_safe), 0.0)
     p = jnp.exp(s - m_safe[:, None])
-    p = jnp.where(ok[None, :], p, 0.0)
+    p = jnp.where(ok, p, 0.0)
     l_scr[...] = (l_scr[...] * corr[:, None]
                   + jnp.sum(p, axis=-1, keepdims=True))
     acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
@@ -91,7 +91,7 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, est_logit_ref, cs_ref, vs_ref,
 def wave_attention_pallas(q, k, v, valid, est_logit, cs, vs, *,
                           softcap=None, block_t: int = 512,
                           interpret: bool = False):
-    """q: (BH, G, hd) f32; k/v: (BH, T, hd) f32; valid: (BH, T) int32;
+    """q: (BH, G, hd) f32; k/v: (BH, T, hd) f32; valid: (BH, 1, T) int32;
     est_logit/cs: (BH, G, E) f32; vs: (BH, E, hd) f32 -> (BH, G, hd) f32.
     T must be a multiple of block_t (ops.py pads)."""
     BH, G, hd = q.shape
@@ -111,7 +111,7 @@ def wave_attention_pallas(q, k, v, valid, est_logit, cs, vs, *,
             pl.BlockSpec((1, G, hd), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, block_t, hd), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, block_t, hd), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_t), lambda b, j: (b, j)),
+            pl.BlockSpec((1, 1, block_t), lambda b, j: (b, 0, j)),
             pl.BlockSpec((1, G, E), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, G, E), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, E, hd), lambda b, j: (b, 0, 0)),
@@ -130,6 +130,12 @@ def wave_attention_pallas(q, k, v, valid, est_logit, cs, vs, *,
 # ---------------------------------------------------------------------------
 # Gather-free paged kernel: steady zone + in-place retrieved clusters.
 #
+# K/V blocks are read from the stores in place. Their token positions are
+# not: a (cap,) int32 row is 32 lanes, and Mosaic DMAs and blocks int32
+# arrays in (1, 128)-lane tiles, so the caller gathers the r retrieved
+# position rows ((BH, r, cap) int32, 4 B per token against 2*hd*2 B of K+V)
+# and the kernel holds them in VMEM for the whole walk.
+#
 # Two cluster-walk flavors share the fold/finalize math:
 #   * BlockSpec walk (``double_buffer=False``): one grid step per retrieved
 #     cluster; the scalar-prefetched ids drive the store BlockSpec index maps
@@ -144,7 +150,7 @@ def wave_attention_pallas(q, k, v, valid, est_logit, cs, vs, *,
 
 def _paged_kernel(idx_ref, rowb_ref, live_ref,
                   q_ref, sk_ref, sv_ref, lk_ref, lv_ref, lp_ref,
-                  kst_ref, vst_ref, pst_ref, el_ref, cs_ref, vs_ref,
+                  kst_ref, vst_ref, rp_ref, el_ref, cs_ref, vs_ref,
                   o_ref, m_scr, l_scr, acc_scr, *,
                   softcap, scale, sink, n_local_blocks, nblocks):
     b = pl.program_id(0)
@@ -173,14 +179,14 @@ def _paged_kernel(idx_ref, rowb_ref, live_ref,
     @pl.when((j >= 1) & (j < 1 + n_local_blocks))
     def _fold_local():
         fold(lk_ref[0].astype(jnp.float32), lv_ref[0].astype(jnp.float32),
-             lp_ref[...])
+             lp_ref[0])
 
     @pl.when(j >= 1 + n_local_blocks)
     def _fold_cluster():
         jc = j - (1 + n_local_blocks)
         fold(kst_ref[0, 0].astype(jnp.float32),
              vst_ref[0, 0].astype(jnp.float32),
-             pst_ref[0], extra_ok=live_ref[b, jc] > 0)
+             rp_ref[0, pl.ds(jc, 1), :], extra_ok=live_ref[b, jc] > 0)
 
     @pl.when(j == nblocks - 1)
     def _finalize():
@@ -235,9 +241,9 @@ def _est_finalize(el_ref, cs_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr):
 
 def _paged_db_kernel(idx_ref, rowb_ref, live_ref,
                      q_ref, sk_ref, sv_ref, lk_ref, lv_ref, lp_ref,
-                     kst_ref, vst_ref, pst_ref, el_ref, cs_ref, vs_ref,
+                     kst_ref, vst_ref, rp_ref, el_ref, cs_ref, vs_ref,
                      o_ref, m_scr, l_scr, acc_scr,
-                     kdb_scr, vdb_scr, pdb_scr, ksem, vsem, psem, *,
+                     kdb_scr, vdb_scr, ksem, vsem, *,
                      softcap, scale, sink, n_local_blocks, nblocks, r):
     """Double-buffered flavor: the stores stay in ANY/HBM; the LAST grid step
     walks all r retrieved clusters, DMA'ing cluster j+1's (cap, hd) blocks
@@ -266,7 +272,7 @@ def _paged_db_kernel(idx_ref, rowb_ref, live_ref,
     @pl.when((j >= 1) & (j < 1 + n_local_blocks))
     def _fold_local():
         fold(lk_ref[0].astype(jnp.float32), lv_ref[0].astype(jnp.float32),
-             lp_ref[...])
+             lp_ref[0])
 
     @pl.when(j == nblocks - 1)
     def _fold_clusters_finalize():
@@ -277,8 +283,6 @@ def _paged_db_kernel(idx_ref, rowb_ref, live_ref,
                                       ksem.at[slot]),
                 pltpu.make_async_copy(vst_ref.at[b, cid], vdb_scr.at[slot],
                                       vsem.at[slot]),
-                pltpu.make_async_copy(pst_ref.at[b, pl.ds(cid, 1)],
-                                      pdb_scr.at[slot], psem.at[slot]),
             )
 
         for c in dmas(0, 0):                        # warm up: cluster 0
@@ -297,7 +301,7 @@ def _paged_db_kernel(idx_ref, rowb_ref, live_ref,
                 c.wait()
             fold(kdb_scr[cur].astype(jnp.float32),
                  vdb_scr[cur].astype(jnp.float32),
-                 pdb_scr[cur], extra_ok=live_ref[b, jc] > 0)
+                 rp_ref[0, pl.ds(jc, 1), :], extra_ok=live_ref[b, jc] > 0)
             return carry
 
         jax.lax.fori_loop(0, r, body, 0)
@@ -306,7 +310,7 @@ def _paged_db_kernel(idx_ref, rowb_ref, live_ref,
 
 def paged_wave_attention_pallas(idx, rowb, live, q, sink_k, sink_v,
                                 local_k, local_v, local_pos,
-                                k_store, v_store, pos_store,
+                                k_store, v_store, ret_pos,
                                 est_logit, cs, vs, *,
                                 sink_len: int, softcap=None,
                                 block_l: int = 512,
@@ -318,10 +322,12 @@ def paged_wave_attention_pallas(idx, rowb, live, q, sink_k, sink_v,
     prefetch); rowb: (BH, 2) int32 [window_lo (exclusive), q_pos (inclusive)];
     q: (BH, G, hd) f32; sink_k/v: (BH, Ss, hd) — slot t holds token t, slots
     >= ``sink_len`` are alignment padding; local_k/v: (BH, Lp, hd) with
-    local_pos (BH, Lp) int32 (-1 = empty, Lp a multiple of block_l);
-    k/v/pos_store: (BH, M, cap, hd) / (BH, M, cap) — read IN PLACE, one
-    (cap, hd) block per retrieved cluster; est_logit/cs: (BH, G, E) f32 f32;
-    vs: (BH, E, hd) f32. Returns (BH, G, hd) f32.
+    local_pos (BH, 1, Lp) int32 (-1 = empty, Lp a multiple of block_l);
+    k/v_store: (BH, M, cap, hd) — read IN PLACE, one (cap, hd) block per
+    retrieved cluster; ret_pos: (BH, r, cap) int32 positions of the
+    retrieved blocks (gathered by the caller, see the section note);
+    est_logit/cs: (BH, G, E) f32; vs: (BH, E, hd) f32. Returns (BH, G, hd)
+    f32.
 
     ``idx`` may address any block store with a (BH, N, cap, ...) layout —
     the monolithic cluster stores (direct path, ids = cluster ids) or the
@@ -336,7 +342,7 @@ def paged_wave_attention_pallas(idx, rowb, live, q, sink_k, sink_v,
     (paged-attention idiom; the automatic pipeline moves the blocks).
     """
     BH, G, hd = q.shape
-    M, cap = k_store.shape[1], k_store.shape[2]
+    cap = k_store.shape[2]
     r = idx.shape[1]
     Ss = sink_k.shape[1]
     Lp = local_k.shape[1]
@@ -347,11 +353,9 @@ def paged_wave_attention_pallas(idx, rowb, live, q, sink_k, sink_v,
     scale = 1.0 / math.sqrt(hd)
 
     lmap = lambda b, j, *_: (b, jnp.clip(j - 1, 0, nlb - 1), 0)
-    lpmap = lambda b, j, *_: (b, jnp.clip(j - 1, 0, nlb - 1))
+    lpmap = lambda b, j, *_: (b, 0, jnp.clip(j - 1, 0, nlb - 1))
     cmap = lambda b, j, idx_ref, *_: \
         (b, idx_ref[b, jnp.clip(j - 1 - nlb, 0, r - 1)], 0, 0)
-    cpmap = lambda b, j, idx_ref, *_: \
-        (b, idx_ref[b, jnp.clip(j - 1 - nlb, 0, r - 1)], 0)
     park = lambda b, j, *_: (b, 0, 0)
 
     scratch = [
@@ -364,15 +368,12 @@ def paged_wave_attention_pallas(idx, rowb, live, q, sink_k, sink_v,
                                  scale=scale, sink=sink_len,
                                  n_local_blocks=nlb, nblocks=nblocks, r=r)
         store_specs = [
-            pl.BlockSpec(memory_space=pltpu.ANY),               # k_store
-            pl.BlockSpec(memory_space=pltpu.ANY),               # v_store
-            pl.BlockSpec(memory_space=pltpu.ANY),               # pos_store
+            pl.BlockSpec(memory_space=pl.ANY),               # k_store
+            pl.BlockSpec(memory_space=pl.ANY),               # v_store
         ]
         scratch = scratch + [
             pltpu.VMEM((2, cap, hd), k_store.dtype),            # k double buf
             pltpu.VMEM((2, cap, hd), v_store.dtype),            # v double buf
-            pltpu.VMEM((2, 1, cap), pos_store.dtype),           # pos double buf
-            pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ]
@@ -383,7 +384,6 @@ def paged_wave_attention_pallas(idx, rowb, live, q, sink_k, sink_v,
         store_specs = [
             pl.BlockSpec((1, 1, cap, hd), cmap),                # k_store
             pl.BlockSpec((1, 1, cap, hd), cmap),                # v_store
-            pl.BlockSpec((1, 1, cap), cpmap),                   # pos_store
         ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -395,8 +395,9 @@ def paged_wave_attention_pallas(idx, rowb, live, q, sink_k, sink_v,
             pl.BlockSpec((1, Ss, hd), park),                    # sink_v
             pl.BlockSpec((1, block_l, hd), lmap),               # local_k
             pl.BlockSpec((1, block_l, hd), lmap),               # local_v
-            pl.BlockSpec((1, block_l), lpmap),                  # local_pos
+            pl.BlockSpec((1, 1, block_l), lpmap),               # local_pos
         ] + store_specs + [
+            pl.BlockSpec((1, r, cap), park),                    # ret_pos
             pl.BlockSpec((1, G, E), park),                      # est_logit
             pl.BlockSpec((1, G, E), park),                      # cs
             pl.BlockSpec((1, E, hd), park),                     # vs
@@ -410,4 +411,4 @@ def paged_wave_attention_pallas(idx, rowb, live, q, sink_k, sink_v,
         out_shape=jax.ShapeDtypeStruct((BH, G, hd), jnp.float32),
         interpret=interpret,
     )(idx, rowb, live, q, sink_k, sink_v, local_k, local_v, local_pos,
-      k_store, v_store, pos_store, est_logit, cs, vs)
+      k_store, v_store, ret_pos, est_logit, cs, vs)

@@ -54,7 +54,7 @@ def wave_attention_merge(qg, k_exec, v_exec, valid, est_logit, cs_e, vs_e, *,
     q = flat(qg).astype(f32)
     k = flat(k_exec).astype(f32)
     v = flat(v_exec).astype(f32)
-    ok = flat(valid).astype(jnp.int32)
+    ok = flat(valid).astype(jnp.int32)[:, None, :]           # (BH, 1, T)
     el = flat(est_logit).astype(f32)
     cs = flat(cs_e).astype(f32)
     vs = flat(vs_e).astype(f32)
@@ -62,7 +62,7 @@ def wave_attention_merge(qg, k_exec, v_exec, valid, est_logit, cs_e, vs_e, *,
     bt = min(block_t, max(128, T))
     k, _ = _pad_to(k, 1, bt)
     v, _ = _pad_to(v, 1, bt)
-    ok, _ = _pad_to(ok, 1, bt)                      # pads are 0 => invalid
+    ok, _ = _pad_to(ok, 2, bt)                      # pads are 0 => invalid
     el = jnp.pad(el, ((0, 0), (0, 0), (0, (-E) % 128)), constant_values=NEG)
     cs = jnp.pad(cs, ((0, 0), (0, 0), (0, (-E) % 128)), constant_values=NEG)
     vs, _ = _pad_to(vs, 1, 128)
@@ -98,14 +98,12 @@ def paged_wave_attention(qg, sink_k, sink_v, local_k, local_v, local_pos,
     sees an id-addressed block store.
 
     ``emulate`` (default: follows ``interpret``) swaps the Pallas kernel for
-    ``ref.paged_wave_attention_jnp`` — the same zone-walk in plain jnp. The
-    jax 0.4.x Pallas *interpreter* carries all input refs as mutable loop
-    state (full-store copies every grid step), so the CPU serving path uses
-    the emulation; interpret=True + emulate=False runs the actual kernel
-    through the interpreter (parity tests). ``double_buffer`` selects the
-    kernel's cluster walk: explicit double-buffered DMA (default — cluster
-    j+1 streams while j folds) vs the one-grid-step-per-cluster BlockSpec
-    walk.
+    ``ref.paged_wave_attention_jnp`` — the same zone-walk in plain jnp,
+    which the CPU serving path uses; interpret=True + emulate=False runs the
+    actual kernel through the Pallas interpreter (parity tests).
+    ``double_buffer`` selects the kernel's cluster walk: explicit
+    double-buffered DMA (default — cluster j+1 streams while j folds) vs the
+    one-grid-step-per-cluster BlockSpec walk.
     """
     B, H, G, hd = qg.shape
     sink = sink_k.shape[2]
@@ -132,14 +130,19 @@ def paged_wave_attention(qg, sink_k, sink_v, local_k, local_v, local_pos,
     # Alignment pads touch only the O(steady)-sized zones and the meta-index
     # estimation tensors — never the cluster stores, which flow through
     # unconverted (an outside astype would copy the ENTIRE store; the kernel
-    # casts per block in VMEM).
+    # casts per block in VMEM). Only the retrieved blocks' (cap,) position
+    # rows are gathered: Mosaic cannot DMA or block a 32-lane int32 row.
     sk, _ = _pad_to(flat(sink_k), 1, 16)
     sv, _ = _pad_to(flat(sink_v), 1, 16)
     bl = min(block_l, max(128, Lb))
     lk, _ = _pad_to(flat(local_k), 1, bl)
     lv, _ = _pad_to(flat(local_v), 1, bl)
     lp = flat(local_pos).astype(jnp.int32)
-    lp = jnp.pad(lp, ((0, 0), (0, lk.shape[1] - Lb)), constant_values=-1)
+    lp = jnp.pad(lp, ((0, 0), (0, lk.shape[1] - Lb)),
+                 constant_values=-1)[:, None, :]              # (BH, 1, Lp)
+    idx = flat(idx_r).astype(jnp.int32)
+    rp = jnp.take_along_axis(flat(pos_store), idx[..., None],
+                             axis=1).astype(jnp.int32)        # (BH, r, cap)
     el = flat(est_logit).astype(f32)
     cs = flat(cs_e).astype(f32)
     vs = flat(vs_e).astype(f32)
@@ -148,9 +151,8 @@ def paged_wave_attention(qg, sink_k, sink_v, local_k, local_v, local_pos,
     vs, _ = _pad_to(vs, 1, 128)
 
     out = paged_wave_attention_pallas(
-        flat(idx_r).astype(jnp.int32), flat(rowb).astype(jnp.int32),
+        idx, flat(rowb).astype(jnp.int32),
         flat(live).astype(jnp.int32), flat(qg).astype(f32), sk, sv, lk, lv,
-        lp, flat(k_store), flat(v_store), flat(pos_store).astype(jnp.int32),
-        el, cs, vs, sink_len=sink, softcap=softcap, block_l=bl,
+        lp, flat(k_store), flat(v_store), rp, el, cs, vs, sink_len=sink, softcap=softcap, block_l=bl,
         double_buffer=double_buffer, interpret=interpret)
     return out.reshape(B, H, G, hd)

@@ -26,14 +26,14 @@ def paged_wave_attention_jnp(idx, rowb, live, q, sink_k, sink_v,
                              est_logit, cs, vs, *, sink_len: int,
                              softcap=None):
     """Gather-free zone-walk in plain jnp — the interpretable twin of
-    ``kernel.paged_wave_attention_pallas`` (same arguments, same fold order:
-    sink -> local buffer -> one scan step per retrieved cluster -> estimation
-    finalize). This is what "fused" resolves to on CPU: the jax 0.4.x Pallas
-    interpreter carries every input ref as mutable while-loop state and
-    copies the full cluster stores each step, defeating the kernel's point;
-    this path keeps the gather-free dataflow — the ``lax.scan`` body slices
-    ONE (cap, hd) block per row per step, so no (BH, r, cap, hd) gather temp
-    and no execution-buffer concat ever materializes.
+    ``kernel.paged_wave_attention_pallas`` (same fold order: sink -> local
+    buffer -> one scan step per retrieved cluster -> estimation finalize;
+    same arguments, except that it takes the whole ``pos_store`` and a flat
+    (BH, Lb) ``local_pos``). This is what "fused" resolves to on CPU. It
+    keeps the gather-free dataflow — the
+    ``lax.scan`` body slices ONE (cap, hd) block per row per step, so no
+    (BH, r, cap, hd) gather temp and no execution-buffer concat ever
+    materializes.
 
     Like the kernel, ``idx`` is just an address into the (BH, N, cap, ...)
     block store handed in: cluster ids against the monolithic stores (direct

@@ -19,6 +19,7 @@ import jax
 import numpy as np
 
 from repro.configs.registry import get_config, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving.engine import Request, ServeEngine
 
@@ -83,6 +84,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
     lens = [int(x) for x in args.prompt_lens.split(",")]

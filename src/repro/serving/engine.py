@@ -92,6 +92,7 @@ class ServeMetrics:
     decode_s: float = 0.0
     tokens_out: int = 0
     steps: int = 0                      # decode steps executed
+    flushes: int = 0                    # decode-time index updates run
     occupied_slot_steps: int = 0        # sum over steps of active slots
     n_slots: int = 0
     ttft_s: List[float] = field(default_factory=list)
@@ -1244,6 +1245,7 @@ class ServeEngine:
                     state = plane.flush(state, rows)
                 else:
                     state = flush(state)
+                metrics.flushes += 1
                 staged[rows] -= cfg.retro.update_segment
         if plane is not None:
             plane.export_stats(metrics)

