@@ -26,6 +26,7 @@ from repro.configs.base import RetroConfig
 from repro.core.wave_index import WaveState
 from repro.core.zones import ZonePlan
 from repro.models.layers import soft_cap
+from repro.serving import trace
 
 NEG = -1e30
 
@@ -262,6 +263,7 @@ def wave_attention_decode(q: jax.Array, state: WaveState, retro: RetroConfig,
         return_parts=return_parts)
 
 
+@trace.scope(trace.RANK)
 def wave_decode_rank(qg, state: WaveState, retro: RetroConfig, plan: ZonePlan,
                      *, window: Optional[jax.Array] = None,
                      softcap: Optional[float] = None,
@@ -292,6 +294,7 @@ def wave_decode_rank(qg, state: WaveState, retro: RetroConfig, plan: ZonePlan,
     return idx_r, est_logit, cs_e, vs_e
 
 
+@trace.scope(trace.ATTEND)
 def wave_attention_attend(q, state: WaveState, retro: RetroConfig,
                           plan: ZonePlan, idx, est_logit, cs_e, vs_e, *,
                           kv_src=None, window: Optional[jax.Array] = None,

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.configs.base import RetroConfig
 from repro.core.clustering import (ClusterResult, cluster_segment,
                                    segmented_cluster)
+from repro.serving import trace
 
 
 class WaveState(NamedTuple):
@@ -353,35 +354,38 @@ def prefill_append_chunk(cp: ChunkedPrefill, k_chunk: jax.Array,
     sink = retro.sink
     clens = jnp.full((B,), C, jnp.int32) if chunk_lens is None \
         else jnp.asarray(chunk_lens, jnp.int32)
-    kc = jnp.swapaxes(k_chunk, 1, 2)                        # (B, H, C, hd)
-    vc = jnp.swapaxes(v_chunk, 1, 2)
+    with trace.scope(trace.CACHE_UPDATE):
+        kc = jnp.swapaxes(k_chunk, 1, 2)                    # (B, H, C, hd)
+        vc = jnp.swapaxes(v_chunk, 1, 2)
 
-    j = jnp.arange(C, dtype=jnp.int32)[None, :]             # (1, C)
-    p = cp.seen[:, None] + j                                # (B, C) abs pos
-    valid = j < clens[:, None]
+        j = jnp.arange(C, dtype=jnp.int32)[None, :]         # (1, C)
+        p = cp.seen[:, None] + j                            # (B, C) abs pos
+        valid = j < clens[:, None]
 
-    # scatter with out-of-range index => dropped write (per-row routing)
-    sink_idx = jnp.where(valid & (p < sink), p, sink)
-    j0 = jnp.clip(sink - cp.seen, 0, C)                     # first staged j
-    stage_cap = cp.stage_k.shape[2]
-    stage_idx = jnp.where(valid & (p >= sink),
-                          cp.staged[:, None] + j - j0[:, None], stage_cap)
+        # scatter with out-of-range index => dropped write (per-row routing)
+        sink_idx = jnp.where(valid & (p < sink), p, sink)
+        j0 = jnp.clip(sink - cp.seen, 0, C)                 # first staged j
+        stage_cap = cp.stage_k.shape[2]
+        stage_idx = jnp.where(valid & (p >= sink),
+                              cp.staged[:, None] + j - j0[:, None], stage_cap)
 
-    scat = scatter_chunk_rows
-    state = cp.state._replace(sink_k=scat(cp.state.sink_k, kc, sink_idx),
-                              sink_v=scat(cp.state.sink_v, vc, sink_idx))
-    cp = ChunkedPrefill(
-        state=state,
-        stage_k=scat(cp.stage_k, kc, stage_idx),
-        stage_v=scat(cp.stage_v, vc, stage_idx),
-        staged=cp.staged + (clens - jnp.clip(sink - cp.seen, 0, clens)),
-        seen=cp.seen + clens)
+        scat = scatter_chunk_rows
+        state = cp.state._replace(sink_k=scat(cp.state.sink_k, kc, sink_idx),
+                                  sink_v=scat(cp.state.sink_v, vc, sink_idx))
+        cp = ChunkedPrefill(
+            state=state,
+            stage_k=scat(cp.stage_k, kc, stage_idx),
+            stage_v=scat(cp.stage_v, vc, stage_idx),
+            staged=cp.staged + (clens - jnp.clip(sink - cp.seen, 0, clens)),
+            seen=cp.seen + clens)
     # a C-token chunk can complete at most ceil(C / segment) segments
-    for _ in range(-(-C // retro.prefill_segment)):
-        cp = _flush_stage(cp, retro)
+    with trace.scope(trace.INDEX_BUILD):
+        for _ in range(-(-C // retro.prefill_segment)):
+            cp = _flush_stage(cp, retro)
     return cp
 
 
+@trace.scope(trace.INDEX_BUILD)
 def prefill_finalize(cp: ChunkedPrefill, retro: RetroConfig,
                      total_len: int) -> WaveState:
     """Close a streaming build: cluster the partial tail segment and install
@@ -420,6 +424,7 @@ def prefill_finalize(cp: ChunkedPrefill, retro: RetroConfig,
         length=cp.seen)
 
 
+@trace.scope(trace.CACHE_UPDATE)
 def append_token(state: WaveState, k_new: jax.Array, v_new: jax.Array,
                  active: Optional[jax.Array] = None) -> WaveState:
     """Append one generated token's (B, H, hd) K/V to the local buffer.

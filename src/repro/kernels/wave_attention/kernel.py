@@ -405,10 +405,13 @@ def paged_wave_attention_pallas(idx, rowb, live, q, sink_k, sink_v,
         out_specs=pl.BlockSpec((1, G, hd), park),
         scratch_shapes=scratch,
     )
+    # a stable name: the HLO custom call (and the profile's op events) are
+    # named after it, whatever Python function wraps the call
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, G, hd), jnp.float32),
         interpret=interpret,
+        name="paged_wave_attention",
     )(idx, rowb, live, q, sink_k, sink_v, local_k, local_v, local_pos,
       k_store, v_store, ret_pos, est_logit, cs, vs)

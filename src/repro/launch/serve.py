@@ -129,7 +129,8 @@ def main():
     for i, r in enumerate(reqs):
         status = "" if r.status == "ok" else f" [{r.status}]"
         print(f"  req {i}: prompt {len(r.prompt)}, out {len(r.out_tokens)}, "
-              f"ttft {r.ttft_s:.2f}s, decode {r.decode_tps:.1f} tok/s"
+              f"ttft {r.ttft_s:.2f}s (queue {r.queue_s:.2f}s + admit "
+              f"{r.admit_s:.2f}s), decode {r.decode_tps:.1f} tok/s"
               f"{status}")
     print("sample output tokens:", reqs[0].out_tokens[:10])
 

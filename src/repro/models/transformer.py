@@ -24,6 +24,7 @@ from repro.core.wave_index import (WaveState, append_token,
 from repro.core.zones import ZonePlan, plan_zones
 from repro.models import layers as L
 from repro.models.moe import init_moe, moe_apply_grouped
+from repro.serving import trace
 
 GLOBAL_WINDOW = 1.0e9   # "no sliding window" sentinel (traced-friendly)
 
@@ -316,37 +317,45 @@ def prefill_chunk(params, cfg: ModelConfig, tokens, state: PrefillChunkState,
         else jnp.asarray(chunk_lens, jnp.int32)
     t0 = state.cache.length[0]                              # (B,) shared by layers
     positions = t0[:, None] + jnp.arange(C)                 # (B, C) per-row
-    x = params["embed"][tokens] * math.sqrt(cfg.d_model)
-    if patch_embeds is not None:
-        P = patch_embeds.shape[1]
-        pe = jnp.take_along_axis(patch_embeds,
-                                 jnp.clip(positions, 0, P - 1)[..., None],
-                                 axis=1)
-        x = jnp.where((positions < P)[..., None], pe.astype(x.dtype), x)
+    with trace.scope(trace.EMBED):
+        x = params["embed"][tokens] * math.sqrt(cfg.d_model)
+        if patch_embeds is not None:
+            P = patch_embeds.shape[1]
+            pe = jnp.take_along_axis(patch_embeds,
+                                     jnp.clip(positions, 0, P - 1)[..., None],
+                                     axis=1)
+            x = jnp.where((positions < P)[..., None], pe.astype(x.dtype), x)
 
     def layer_fn(x, xs):
         lp, cache_l, wave_l, window = xs
-        h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k, v = L.attention_qkv(lp["attn"], h, a.n_heads, a.n_kv_heads,
-                                  a.head_dim, positions, a.rope_theta)
-        cache_l = _cache_append_chunk(cache_l, k, v, clens)
-        o = _chunk_attention(q, cache_l, t0, clens, window=window,
-                             softcap=a.softcap)
-        x = x + o.reshape(B, C, -1) @ lp["attn"]["wo"]
-        h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        y, _ = _ffn(lp, h, cfg)
+        with trace.scope(trace.QKV):
+            h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q, k, v = L.attention_qkv(lp["attn"], h, a.n_heads, a.n_kv_heads,
+                                      a.head_dim, positions, a.rope_theta)
+        with trace.scope(trace.CACHE_UPDATE):
+            cache_l = _cache_append_chunk(cache_l, k, v, clens)
+        with trace.scope(trace.ATTEND):
+            o = _chunk_attention(q, cache_l, t0, clens, window=window,
+                                 softcap=a.softcap)
+            x = x + o.reshape(B, C, -1) @ lp["attn"]["wo"]
+        with trace.scope(trace.FFN):
+            h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
+            y, _ = _ffn(lp, h, cfg)
+            x = x + y
         if runtime == "retro":
             wave_l = prefill_append_chunk(wave_l, k, v, retro, clens)
-        return x + y, (cache_l, wave_l)
+        return x, (cache_l, wave_l)
 
     x, (cache, wave) = jax.lax.scan(
         layer_fn, x,
         (params["layers"], state.cache, state.wave, params["window"]))
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(clens - 1, 0)[:, None, None].astype(jnp.int32),
-        axis=1)[:, 0]
-    return unembed(params, cfg, last), PrefillChunkState(cache=cache, wave=wave)
+    with trace.scope(trace.UNEMBED):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        last = jnp.take_along_axis(
+            x, jnp.maximum(clens - 1, 0)[:, None, None].astype(jnp.int32),
+            axis=1)[:, 0]
+        logits = unembed(params, cfg, last)
+    return logits, PrefillChunkState(cache=cache, wave=wave)
 
 
 def finalize_prefill_chunk(cfg: ModelConfig, state: PrefillChunkState, *,
@@ -385,18 +394,21 @@ def decode_step(params, cfg: ModelConfig, state: ServeState, token, *,
     a = cfg.attn
     retro = cfg.retro
     impl = wa.resolve_attn_impl(attn_impl or retro.attn_impl)
-    x = params["embed"][token] * math.sqrt(cfg.d_model)     # (B, D)
+    with trace.scope(trace.EMBED):
+        x = params["embed"][token] * math.sqrt(cfg.d_model)     # (B, D)
     B = x.shape[0]
 
     def layer_fn(x, xs):
         lp, lstate, window = xs
         pos = lstate.length                                  # (B,) new token position
-        h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k, v = L.attention_qkv(
-            lp["attn"], h[:, None, :], a.n_heads, a.n_kv_heads, a.head_dim,
-            pos[:, None], a.rope_theta)
-        q, k, v = q[:, 0], k[:, 0], v[:, 0]                  # (B, H*, hd)
+        with trace.scope(trace.QKV):
+            h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q, k, v = L.attention_qkv(
+                lp["attn"], h[:, None, :], a.n_heads, a.n_kv_heads,
+                a.head_dim, pos[:, None], a.rope_theta)
+            q, k, v = q[:, 0], k[:, 0], v[:, 0]              # (B, H*, hd)
         if runtime == "retro":
+            # append_token, the rank and the attend half name themselves
             lstate = append_token(lstate, k, v, active=active)
             out = wa.wave_attention_decode(q, lstate, retro, plan,
                                            window=window, softcap=a.softcap,
@@ -405,18 +417,25 @@ def decode_step(params, cfg: ModelConfig, state: ServeState, token, *,
                 lstate = maybe_flush(lstate, retro)
             o = out.out
         else:
-            lstate = wa.dense_cache_append(lstate, k, v, active=active)
-            o = wa.full_attention_decode(q, lstate, window=window,
-                                         softcap=a.softcap)
-        x = x + o.reshape(B, -1) @ lp["attn"]["wo"]
-        h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        y, _ = _ffn(lp, h, cfg)
-        return x + y, lstate
+            with trace.scope(trace.CACHE_UPDATE):
+                lstate = wa.dense_cache_append(lstate, k, v, active=active)
+            with trace.scope(trace.ATTEND):
+                o = wa.full_attention_decode(q, lstate, window=window,
+                                             softcap=a.softcap)
+        with trace.scope(trace.ATTEND):
+            x = x + o.reshape(B, -1) @ lp["attn"]["wo"]
+        with trace.scope(trace.FFN):
+            h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
+            y, _ = _ffn(lp, h, cfg)
+            x = x + y
+        return x, lstate
 
     x, kv = jax.lax.scan(layer_fn, x,
                          (params["layers"], state.kv, params["window"]))
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return unembed(params, cfg, x), ServeState(kv=kv)
+    with trace.scope(trace.UNEMBED):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = unembed(params, cfg, x)
+    return logits, ServeState(kv=kv)
 
 
 # ---------------------------------------------------------------------------
@@ -544,13 +563,15 @@ def live_wave_state(live: Dict[str, jax.Array]) -> WaveState:
 
 def decode_embed(params, cfg: ModelConfig, token):
     """token: (B,) int32 -> (B, D) embedded decode input."""
-    return params["embed"][token] * math.sqrt(cfg.d_model)
+    with trace.scope(trace.EMBED):
+        return params["embed"][token] * math.sqrt(cfg.d_model)
 
 
 def decode_unembed(params, cfg: ModelConfig, x):
     """(B, D) final hidden -> (B, V) logits (final norm + unembed)."""
-    return unembed(params, cfg, L.rms_norm(x, params["final_norm"],
-                                           cfg.norm_eps))
+    with trace.scope(trace.UNEMBED):
+        return unembed(params, cfg, L.rms_norm(x, params["final_norm"],
+                                               cfg.norm_eps))
 
 
 def offload_decode_rank(lp, window, cfg: ModelConfig, live: Dict, x, *,
@@ -563,11 +584,12 @@ def offload_decode_rank(lp, window, cfg: ModelConfig, live: Dict, x, *,
     B = x.shape[0]
     lstate = live_wave_state(live)
     pos = lstate.length                                  # (B,) new token pos
-    h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
-    q, k, v = L.attention_qkv(
-        lp["attn"], h[:, None, :], a.n_heads, a.n_kv_heads, a.head_dim,
-        pos[:, None], a.rope_theta)
-    q, k, v = q[:, 0], k[:, 0], v[:, 0]                  # (B, H*, hd)
+    with trace.scope(trace.QKV):
+        h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
+        q, k, v = L.attention_qkv(
+            lp["attn"], h[:, None, :], a.n_heads, a.n_kv_heads, a.head_dim,
+            pos[:, None], a.rope_theta)
+        q, k, v = q[:, 0], k[:, 0], v[:, 0]              # (B, H*, hd)
     lstate = append_token(lstate, k, v, active=active)
     G = a.n_heads // a.n_kv_heads
     qg = q.reshape(B, a.n_kv_heads, G, a.head_dim)
@@ -599,10 +621,12 @@ def offload_decode_attend(lp, window, cfg: ModelConfig, live: Dict, x, ctx,
         q, lstate, retro, plan, idx_slots, est_logit, cs_e, vs_e,
         kv_src=(cache_k, cache_v, cache_pos), window=window,
         softcap=a.softcap, impl=impl, valid=valid, cover=cover).out
-    x = x + out.reshape(B, -1) @ lp["attn"]["wo"]
-    h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-    y, _ = _ffn(lp, h, cfg)
-    return x + y
+    with trace.scope(trace.ATTEND):
+        x = x + out.reshape(B, -1) @ lp["attn"]["wo"]
+    with trace.scope(trace.FFN):
+        h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
+        y, _ = _ffn(lp, h, cfg)
+        return x + y
 
 
 def offload_flush(cfg: ModelConfig, live_stacked: Dict, rows):

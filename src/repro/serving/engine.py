@@ -66,6 +66,7 @@ from repro.core.zones import plan_zones
 from repro.models import model as M
 from repro.models.model import ATTN_FAMILIES
 from repro.models.transformer import HOT_FIELDS, LIVE_FIELDS
+from repro.serving import trace
 
 
 @dataclass
@@ -76,7 +77,9 @@ class Request:
     done: bool = False
     extra: Optional[Dict] = None        # per-request prefill extras (e.g. vlm)
     # ---- filled by the engine ----
-    ttft_s: float = 0.0                 # enqueue -> first token
+    ttft_s: float = 0.0                 # enqueue -> first token (queue + admit)
+    queue_s: float = 0.0                # enqueue -> its first chunk dispatched
+    admit_s: float = 0.0                # first chunk dispatched -> first token
     decode_tps: float = 0.0             # this request's decode tokens/s
     # "ok" | "timeout" (max-decode-steps watchdog) | "error" (unrecoverable
     # transport fault) — structured per-request completion status; non-ok
@@ -90,6 +93,11 @@ class ServeMetrics:
     sampled tokens of real requests are counted."""
     prefill_s: float = 0.0
     decode_s: float = 0.0
+    # host time blocked in the loop's two readbacks: the lagged id harvest
+    # (inside decode_s) and the coalesced first-token sample (inside
+    # prefill_s) — high while the device sets the pace, low when the host does
+    harvest_wait_s: float = 0.0
+    first_token_wait_s: float = 0.0
     tokens_out: int = 0
     steps: int = 0                      # decode steps executed
     flushes: int = 0                    # decode-time index updates run
@@ -656,7 +664,8 @@ class _OffloadPlane:
             self.trace("readback_ids", l, "sync", t)
             ids = np.asarray(idx_r)  # retrolint: sync(per-layer id readback)
             self.trace("translate", l, "host", t)
-            idx_slots, valid, mk, mv, mp = self._translate(l, ids, active)
+            with trace.span(trace.TRANSLATE):
+                idx_slots, valid, mk, mv, mp = self._translate(l, ids, active)
             if self.pending_adm[l] is None:     # warm cache: staging only
                 self.trace("cache_stage", l, "dispatch", t)
                 self.cache_k[l], self.cache_v[l], self.cache_p[l] = \
@@ -680,7 +689,8 @@ class _OffloadPlane:
             new_hot.append(live)
             if l + 1 < self.L:      # pipeline: next rank before this drain
                 nxt = self._launch_rank(l + 1, kv, x, act_dev, t)
-            queued = self._drain_admissions(l, active)  # off the hot path
+            with trace.span(trace.DRAIN_ADMISSIONS):    # off the hot path
+                queued = self._drain_admissions(l, active)
             self.trace("drain_admissions", l, "host", t, queued=queued)
         self.trace("unembed_logits", -1, "dispatch", t)
         logits = self._unembed(self.params, x)
@@ -1072,105 +1082,113 @@ class ServeEngine:
                 or prev_sampled is not None:
             # ---- admission: one prefill chunk per admitting slot ----------
             t0 = time.perf_counter()
-            completed: List[Tuple[int, _Admission]] = []
-            for i in range(B):
-                if not chunked:
-                    if active[i] or slots[i] is not None or not queue:
+            with trace.span(trace.ADMIT):
+                completed: List[Tuple[int, _Admission]] = []
+                for i in range(B):
+                    if not chunked:
+                        if active[i] or slots[i] is not None or not queue:
+                            continue
+                        req = queue.popleft()
+                        L = len(req.prompt)
+                        S_b = min(self._bucket(L), max_ctx)
+                        assert S_b >= L
+                        toks = np.zeros((1, S_b), np.int32)
+                        toks[0, :L] = req.prompt
+                        batch = {"tokens": jnp.asarray(toks)}
+                        if req.extra:
+                            batch.update(req.extra)
+                        prefill = self._prefill_fn(S_b, max_ctx)
+                        req.queue_s = time.perf_counter() - t_start
+                        logits, st1 = prefill(self.params, batch,
+                                              jnp.asarray([L], jnp.int32))
+                        state = self._graft(state, st1, jnp.asarray(i, jnp.int32))
+                        if plane is not None:   # device->host store offload
+                            plane.admit_slot(i, st1)
+                        completed.append((i, _Admission(req=req, logits=logits,
+                                                        consumed=L)))
                         continue
-                    req = queue.popleft()
-                    L = len(req.prompt)
-                    S_b = min(self._bucket(L), max_ctx)
-                    assert S_b >= L
-                    toks = np.zeros((1, S_b), np.int32)
-                    toks[0, :L] = req.prompt
-                    batch = {"tokens": jnp.asarray(toks)}
-                    if req.extra:
-                        batch.update(req.extra)
-                    prefill = self._prefill_fn(S_b, max_ctx)
-                    logits, st1 = prefill(self.params, batch,
-                                          jnp.asarray([L], jnp.int32))
-                    state = self._graft(state, st1, jnp.asarray(i, jnp.int32))
-                    if plane is not None:   # device->host store offload
-                        plane.admit_slot(i, st1)
-                    completed.append((i, _Admission(req=req, logits=logits,
-                                                    consumed=L)))
-                    continue
-                if admitting[i] is None and not active[i] \
-                        and slots[i] is None and queue:
-                    req = queue.popleft()
-                    admitting[i] = _Admission(
-                        req=req,
-                        cstate=M.make_prefill_chunk_state(
-                            cfg, 1, max_ctx, runtime=rt,
-                            chunk=self.prefill_chunk,
-                            gen_headroom=self.gen_headroom))
-                adm = admitting[i]
-                if adm is None:
-                    continue
-                L, C = len(adm.req.prompt), self.prefill_chunk
-                n = min(C, L - adm.consumed)
-                toks = np.zeros((1, C), np.int32)
-                toks[0, :n] = adm.req.prompt[adm.consumed:adm.consumed + n]
-                chunk, chunk_pe = self._chunk_fns(max_ctx)
-                extra = adm.req.extra or {}
-                if set(extra) == {"patch_embeds"}:
-                    adm.logits, adm.cstate = chunk_pe(
-                        self.params, adm.cstate, jnp.asarray(toks),
-                        jnp.asarray([n], jnp.int32), extra["patch_embeds"])
-                elif extra:     # uncompiled fallback for exotic extras
-                    adm.logits, adm.cstate = M.apply_prefill_chunk(
-                        self.params, cfg,
-                        {"tokens": jnp.asarray(toks), **extra},
-                        adm.cstate, runtime=rt,
-                        chunk_lens=jnp.asarray([n], jnp.int32))
-                else:
-                    adm.logits, adm.cstate = chunk(
-                        self.params, adm.cstate, jnp.asarray(toks),
-                        jnp.asarray([n], jnp.int32))
-                adm.consumed += n
-                if adm.consumed >= L:
-                    fin = self._finalize_fn(L, max_ctx)
-                    if plane is not None:
-                        state, st1 = fin(state, adm.cstate,
-                                         jnp.asarray(i, jnp.int32))
-                        plane.admit_slot(i, st1)    # device->host offload
+                    if admitting[i] is None and not active[i] \
+                            and slots[i] is None and queue:
+                        req = queue.popleft()
+                        admitting[i] = _Admission(
+                            req=req,
+                            cstate=M.make_prefill_chunk_state(
+                                cfg, 1, max_ctx, runtime=rt,
+                                chunk=self.prefill_chunk,
+                                gen_headroom=self.gen_headroom))
+                    adm = admitting[i]
+                    if adm is None:
+                        continue
+                    L, C = len(adm.req.prompt), self.prefill_chunk
+                    n = min(C, L - adm.consumed)
+                    toks = np.zeros((1, C), np.int32)
+                    toks[0, :n] = adm.req.prompt[adm.consumed:adm.consumed + n]
+                    chunk, chunk_pe = self._chunk_fns(max_ctx)
+                    extra = adm.req.extra or {}
+                    if adm.consumed == 0:
+                        adm.req.queue_s = time.perf_counter() - t_start
+                    if set(extra) == {"patch_embeds"}:
+                        adm.logits, adm.cstate = chunk_pe(
+                            self.params, adm.cstate, jnp.asarray(toks),
+                            jnp.asarray([n], jnp.int32), extra["patch_embeds"])
+                    elif extra:     # uncompiled fallback for exotic extras
+                        adm.logits, adm.cstate = M.apply_prefill_chunk(
+                            self.params, cfg,
+                            {"tokens": jnp.asarray(toks), **extra},
+                            adm.cstate, runtime=rt,
+                            chunk_lens=jnp.asarray([n], jnp.int32))
                     else:
-                        state = fin(state, adm.cstate,
-                                    jnp.asarray(i, jnp.int32))
-                    adm.cstate = None
-                    admitting[i] = None
-                    completed.append((i, adm))
+                        adm.logits, adm.cstate = chunk(
+                            self.params, adm.cstate, jnp.asarray(toks),
+                            jnp.asarray([n], jnp.int32))
+                    adm.consumed += n
+                    if adm.consumed >= L:
+                        fin = self._finalize_fn(L, max_ctx)
+                        if plane is not None:
+                            state, st1 = fin(state, adm.cstate,
+                                             jnp.asarray(i, jnp.int32))
+                            plane.admit_slot(i, st1)    # device->host offload
+                        else:
+                            state = fin(state, adm.cstate,
+                                        jnp.asarray(i, jnp.int32))
+                        adm.cstate = None
+                        admitting[i] = None
+                        completed.append((i, adm))
 
-            if completed:
-                # coalesced first-token sampling: ONE host sync for every
-                # request admitted this iteration
-                key, sub = jax.random.split(key)
-                stacked = jnp.concatenate([a.logits for _, a in completed], 0)
-                first = self._sample(stacked, sub)      # blocks until ready
-                now = time.perf_counter()
-                upd = np.zeros(B, np.int32)
-                mask = np.zeros(B, bool)
-                for (i, adm), tok in zip(completed, first):
-                    req = adm.req
-                    req.ttft_s = now - t_start
-                    req.out_tokens.append(int(tok))
-                    metrics.tokens_out += 1
-                    metrics.ttft_s.append(req.ttft_s)
-                    admit_t[i] = now
-                    slots[i] = req
-                    active[i] = True
-                    slot_steps[i] = 0
-                    upd[i], mask[i] = tok, True
-                    # device local_len after admission: chunked finalize uses
-                    # the true length; a padded blocking prefill uses S_b, but
-                    # _bucket only pads prompts with L >= sink + local, where
-                    # both give exactly ``local`` — the mirror matches either
-                    staged[i] = min(cfg.retro.local,
-                                    max(adm.consumed - cfg.retro.sink, 0))
-                    if len(req.out_tokens) >= req.max_new_tokens:
-                        finish(i, req)
-                tokens_dev = self._merge_tokens(tokens_dev, jnp.asarray(upd),
-                                                jnp.asarray(mask))
+                if completed:
+                    # coalesced first-token sampling: ONE host sync for every
+                    # request admitted this iteration
+                    key, sub = jax.random.split(key)
+                    stacked = jnp.concatenate([a.logits for _, a in completed], 0)
+                    t_wait = time.perf_counter()
+                    with trace.span(trace.FIRST_TOKEN):
+                        first = self._sample(stacked, sub)  # blocks until ready
+                    now = time.perf_counter()
+                    metrics.first_token_wait_s += now - t_wait
+                    upd = np.zeros(B, np.int32)
+                    mask = np.zeros(B, bool)
+                    for (i, adm), tok in zip(completed, first):
+                        req = adm.req
+                        req.admit_s = now - t_start - req.queue_s
+                        req.ttft_s = req.queue_s + req.admit_s
+                        req.out_tokens.append(int(tok))
+                        metrics.tokens_out += 1
+                        metrics.ttft_s.append(req.ttft_s)
+                        admit_t[i] = now
+                        slots[i] = req
+                        active[i] = True
+                        slot_steps[i] = 0
+                        upd[i], mask[i] = tok, True
+                        # device local_len after admission: chunked finalize uses
+                        # the true length; a padded blocking prefill uses S_b, but
+                        # _bucket only pads prompts with L >= sink + local, where
+                        # both give exactly ``local`` — the mirror matches either
+                        staged[i] = min(cfg.retro.local,
+                                        max(adm.consumed - cfg.retro.sink, 0))
+                        if len(req.out_tokens) >= req.max_new_tokens:
+                            finish(i, req)
+                    tokens_dev = self._merge_tokens(tokens_dev, jnp.asarray(upd),
+                                                    jnp.asarray(mask))
             metrics.prefill_s += time.perf_counter() - t0
 
             # ---- one decode step over the whole slot batch -----------------
@@ -1181,13 +1199,14 @@ class ServeEngine:
             did_decode = False
             if active.any():
                 key, sub = jax.random.split(key)
-                if plane is not None:
-                    logits, state = plane.decode_step(state, tokens_dev,
-                                                      active)
-                else:
-                    logits, state = decode(self.params, state, tokens_dev,
-                                           jnp.asarray(active))
-                new_sampled = self._sample_dev(logits, sub)  # device, no sync
+                with trace.span(trace.DECODE):
+                    if plane is not None:
+                        logits, state = plane.decode_step(state, tokens_dev,
+                                                          active)
+                    else:
+                        logits, state = decode(self.params, state, tokens_dev,
+                                               jnp.asarray(active))
+                    new_sampled = self._sample_dev(logits, sub)  # no sync
                 snapshot = [slots[i] if active[i] else None for i in range(B)]
                 metrics.steps += 1
                 metrics.occupied_slot_steps += int(active.sum())
@@ -1215,8 +1234,11 @@ class ServeEngine:
             if prev_sampled is not None:
                 # the decode loop's ONLY sync: step t's ids, harvested one
                 # step late (step t+1 is already dispatched above)
-                ids = np.asarray(prev_sampled)  # retrolint: sync(lagged id harvest)
+                t_wait = time.perf_counter()
+                with trace.span(trace.HARVEST):
+                    ids = np.asarray(prev_sampled)  # retrolint: sync(lagged id harvest)
                 now = time.perf_counter()
+                metrics.harvest_wait_s += now - t_wait
                 delivered = set()
                 for i, req in enumerate(prev_snapshot):
                     if req is None or slots[i] is not req or req.done:
@@ -1241,10 +1263,11 @@ class ServeEngine:
             # ---- per-row masked index update (off the per-step hot path) ---
             if use_flush and (staged >= lbuf).any():
                 rows = staged >= lbuf
-                if plane is not None:
-                    state = plane.flush(state, rows)
-                else:
-                    state = flush(state)
+                with trace.span(trace.FLUSH):
+                    if plane is not None:
+                        state = plane.flush(state, rows)
+                    else:
+                        state = flush(state)
                 metrics.flushes += 1
                 staged[rows] -= cfg.retro.update_segment
         if plane is not None:
