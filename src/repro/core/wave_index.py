@@ -96,26 +96,38 @@ def init_wave_state(B: int, H: int, hd: int, M: int, retro: RetroConfig,
     )
 
 
-def _write_clusters(state: WaveState, res: ClusterResult, offset) -> WaveState:
+def _write_clusters(state: WaveState, res: ClusterResult, offset,
+                    rows: Optional[jax.Array] = None) -> WaveState:
     """Write a block of freshly clustered segments at cluster ``offset``.
 
     res leaves have leading (B, H, k_new, ...); offset is per-row (B,) (a
     scalar broadcasts) and may be traced — rows at different fill levels
     receive their new clusters at different slots.
 
+    ``rows``: optional (B,) bool; unselected rows rewrite the block's old
+    contents and keep their ``n_clusters`` (bit-unchanged, and only the
+    block is read, not the whole store).
+
     ``None`` payload stores (the host-offload live view — k/v/pos live
     host-side) pass through untouched: only the meta index is written.
     """
     B = state.size.shape[0]
     off = jnp.broadcast_to(jnp.asarray(offset, jnp.int32), (B,))
+    k_new = res.size.shape[2]
 
     def upd(store, new):
         if store is None:
             return None
-        def row(sb, nb, ob):
+        def row(sb, nb, ob, keep=None):
             start = (0, ob) + (0,) * (nb.ndim - 2)
-            return jax.lax.dynamic_update_slice(sb, nb.astype(sb.dtype), start)
-        return jax.vmap(row)(store, new, off)
+            nb = nb.astype(sb.dtype)
+            if keep is not None:
+                nb = jnp.where(keep, nb,
+                               jax.lax.dynamic_slice(sb, start, nb.shape))
+            return jax.lax.dynamic_update_slice(sb, nb, start)
+        if rows is None:
+            return jax.vmap(row)(store, new, off)
+        return jax.vmap(row)(store, new, off, rows)
 
     return state._replace(
         k_store=upd(state.k_store, res.k_store),
@@ -126,7 +138,8 @@ def _write_clusters(state: WaveState, res: ClusterResult, offset) -> WaveState:
         size=upd(state.size, res.size),
         stored=upd(state.stored, res.stored),
         max_pos=upd(state.max_pos, res.max_pos),
-        n_clusters=state.n_clusters + res.size.shape[2],
+        n_clusters=state.n_clusters + (
+            k_new if rows is None else jnp.where(rows, k_new, 0)),
     )
 
 
@@ -310,23 +323,38 @@ def _flush_stage(cp: ChunkedPrefill, retro: RetroConfig) -> ChunkedPrefill:
     tokens: the oldest segment then provably ends >= ``local`` before the
     final prompt end, so it is one of the full segments ``prefill_build``
     would cluster. Rows below the threshold pass through bit-unchanged.
+
+    The clustering runs under ``lax.cond`` on whether any row flushes, so a
+    chunk that completes no segment (most chunks of a long prompt, every
+    chunk of one under ``sink + prefill_segment + local``) skips it. Only the
+    staging buffers enter the cond: XLA copies a cond's operands and
+    results, so the cluster stores stay outside and take the new block
+    through a per-row masked write. Inside the model's layer scan the cond
+    is a real branch; under ``vmap`` it lowers to a select that runs both
+    branches, as the ungated flush did.
     """
     seg = retro.prefill_segment
     rows = cp.staged >= seg + retro.local
     start = cp.seen - cp.staged                  # abs position of stage[0]
     pos = start[:, None] + jnp.arange(seg, dtype=jnp.int32)[None, :]
 
-    def row_fn(kk, vv, p):
-        def bh(k1, v1):
-            return cluster_segment(k1[:seg], v1[:seg], p, retro.avg_cluster,
-                                   retro.cluster_cap, retro.kmeans_iters,
-                                   retro.centering)
-        return jax.vmap(bh)(kk, vv)
+    def cluster(stage_k, stage_v, pos):
+        def row_fn(kk, vv, p):
+            def bh(k1, v1):
+                return cluster_segment(k1[:seg], v1[:seg], p,
+                                       retro.avg_cluster, retro.cluster_cap,
+                                       retro.kmeans_iters, retro.centering)
+            return jax.vmap(bh)(kk, vv)
+        return jax.vmap(row_fn)(stage_k, stage_v, pos)
 
-    res = jax.vmap(row_fn)(cp.stage_k, cp.stage_v, pos)
-    flushed = _write_clusters(cp.state, res, cp.state.n_clusters)
+    def skip(*args):        # never written: every row is masked out
+        return jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                            jax.eval_shape(cluster, *args))
+
+    res = jax.lax.cond(jnp.any(rows), cluster, skip,
+                       cp.stage_k, cp.stage_v, pos)
     return ChunkedPrefill(
-        state=_where_rows(rows, flushed, cp.state),
+        state=_write_clusters(cp.state, res, cp.state.n_clusters, rows),
         stage_k=_where_rows(rows, jnp.roll(cp.stage_k, -seg, axis=2),
                             cp.stage_k),
         stage_v=_where_rows(rows, jnp.roll(cp.stage_v, -seg, axis=2),
@@ -383,6 +411,16 @@ def prefill_append_chunk(cp: ChunkedPrefill, k_chunk: jax.Array,
         for _ in range(-(-C // retro.prefill_segment)):
             cp = _flush_stage(cp, retro)
     return cp
+
+
+def stage_flushes(seen: int, n: int, retro: RetroConfig) -> int:
+    """Staging flushes that ``prefill_append_chunk`` runs for a row's chunk
+    of ``n`` valid tokens starting at prompt position ``seen`` (host-side,
+    no device readback). Flushes are greedy, so after ``t`` tokens a row has
+    flushed every segment that ``local`` later tokens follow."""
+    def done(t):
+        return max(0, t - retro.sink - retro.local) // retro.prefill_segment
+    return done(seen + n) - done(seen)
 
 
 @trace.scope(trace.INDEX_BUILD)

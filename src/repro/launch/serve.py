@@ -113,6 +113,8 @@ def main():
           f"decode {m.tokens_out} tokens @ {m.decode_tps:.1f} tok/s, "
           f"slot occupancy {m.slot_occupancy:.2f}, "
           f"itl p50/p99 {m.itl_p50_s * 1e3:.1f}/{m.itl_p99_s * 1e3:.1f} ms")
+    print(f"  index flushes: decode {m.flushes}, admission "
+          f"{m.prefill_stage_flushes}/{m.prefill_chunks} chunks")
     if args.offload:
         print(f"  wave buffer: hit {m.cache_hit_ratio:.3f} "
               f"(effective {m.effective_cache_hit_ratio:.3f}, "

@@ -61,7 +61,7 @@ from repro.configs.base import ModelConfig
 from repro.core.wave_buffer import (BufferStats, FatalTransportError,
                                     FaultProfile, FaultyTransport,
                                     LinkTransport, WaveBuffer)
-from repro.core.wave_index import local_buffer_size
+from repro.core.wave_index import local_buffer_size, stage_flushes
 from repro.core.zones import plan_zones
 from repro.models import model as M
 from repro.models.model import ATTN_FAMILIES
@@ -101,6 +101,10 @@ class ServeMetrics:
     tokens_out: int = 0
     steps: int = 0                      # decode steps executed
     flushes: int = 0                    # decode-time index updates run
+    # chunked admission: chunk calls dispatched, and those whose staging
+    # flush clustered a prompt segment (retro; the other calls skip it)
+    prefill_chunks: int = 0
+    prefill_stage_flushes: int = 0
     occupied_slot_steps: int = 0        # sum over steps of active slots
     n_slots: int = 0
     ttft_s: List[float] = field(default_factory=list)
@@ -1141,6 +1145,10 @@ class ServeEngine:
                         adm.logits, adm.cstate = chunk(
                             self.params, adm.cstate, jnp.asarray(toks),
                             jnp.asarray([n], jnp.int32))
+                    metrics.prefill_chunks += 1
+                    if rt == "retro" and stage_flushes(adm.consumed, n,
+                                                       cfg.retro):
+                        metrics.prefill_stage_flushes += 1
                     adm.consumed += n
                     if adm.consumed >= L:
                         fin = self._finalize_fn(L, max_ctx)

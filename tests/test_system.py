@@ -160,6 +160,46 @@ def test_chunked_admission_matches_blocking(setup):
         assert outs["chunked"] == outs["blocking"], runtime
 
 
+def test_chunked_admission_counts_stage_flushes(setup):
+    """``ServeMetrics`` counts the chunk calls and the calls whose staging
+    flush ran, as a replay of each prompt's staging buffer predicts; a
+    prompt under sink + prefill_segment + local never flushes. The tokens
+    are blocking admission's."""
+    params = setup[0]
+    rx, C = CFG.retro, 96
+    lens, news = [S, 90, 200], [4, 3, 5]
+    assert min(lens) < rx.sink + rx.prefill_segment + rx.local < max(lens)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, CFG.vocab, L).astype(np.int32) for L in lens]
+
+    outs, metrics = {}, {}
+    for mode in ("blocking", "chunked"):
+        eng = ServeEngine(CFG, params, runtime="retro", gen_headroom=256,
+                          max_context=S, admission=mode, prefill_chunk=C)
+        reqs = [Request(prompt=p.copy(), max_new_tokens=n)
+                for p, n in zip(prompts, news)]
+        metrics[mode] = eng.serve(reqs, batch_size=2)
+        outs[mode] = [r.out_tokens for r in reqs]
+    assert outs["chunked"] == outs["blocking"]
+
+    chunks = fired = 0
+    for L in lens:
+        staged = 0
+        for t in range(0, L, C):
+            n = min(C, L - t)
+            staged += n - min(max(rx.sink - t, 0), n)
+            flushed = staged >= rx.prefill_segment + rx.local
+            while staged >= rx.prefill_segment + rx.local:
+                staged -= rx.prefill_segment
+            chunks += 1
+            fired += flushed
+    assert (chunks, fired) == (8, 4)
+    m = metrics["chunked"]
+    assert (m.prefill_chunks, m.prefill_stage_flushes) == (chunks, fired)
+    m = metrics["blocking"]
+    assert (m.prefill_chunks, m.prefill_stage_flushes) == (0, 0)
+
+
 @pytest.mark.slow
 def test_fused_attn_impl_matches_jnp(setup):
     """Acceptance: the gather-free fused decode attention reproduces the jnp

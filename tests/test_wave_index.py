@@ -1,16 +1,19 @@
 """Wave-index construction / update invariants + retrieval quality."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import RetroConfig
-from repro.core.clustering import segmented_cluster, spherical_kmeans
+from repro.core.clustering import (cluster_segment, segmented_cluster,
+                                   spherical_kmeans)
 from repro.core.wave_index import (append_token, flush_segment,
                                    init_chunked_prefill, max_clusters,
                                    maybe_flush, prefill_append_chunk,
                                    prefill_build, prefill_finalize,
-                                   prefill_layout)
+                                   prefill_layout, stage_flushes)
 from repro.core.zones import plan_zones
 from repro.data.pipeline import clustered_keys
 
@@ -267,6 +270,90 @@ def test_chunked_prefill_matches_build_exactly(chunk):
     cp = _feed_chunks(cp, k, v, chunk, jit=(chunk == 256))
     out = prefill_finalize(cp, RETRO, n)
     _assert_states_equal(out, ref)
+
+
+def test_gated_stage_flush_matches_direct_clustering():
+    """Of two rows, one reaches prefill_segment + local in a chunk and one
+    does not: the gated (jitted) flush clusters the first row's oldest
+    segment exactly as ``cluster_segment`` does, shifts its staging buffer
+    by a segment, and leaves the second row bit-unchanged."""
+    B, H, hd, C = 2, 2, 32, 128
+    seg, k_new = RETRO.prefill_segment, RETRO.prefill_segment // RETRO.avg_cluster
+    rng = np.random.default_rng(5)
+    M = max_clusters(1100, RETRO, gen_headroom=128)
+    cp = init_chunked_prefill(B, H, hd, M, RETRO, C, dtype=jnp.float32)
+
+    def chunk():
+        return jnp.asarray(rng.standard_normal((B, C, H, hd)), jnp.float32)
+
+    app = jax.jit(lambda cp, kc, vc, cl: prefill_append_chunk(
+        cp, kc, vc, RETRO, cl))
+    for _ in range(2):      # row 0 stages 252 tokens, row 1 none
+        cp = app(cp, chunk(), chunk(), jnp.asarray([C, 0], jnp.int32))
+    kc, vc, cl = chunk(), chunk(), jnp.full((B,), C, jnp.int32)
+    out = app(cp, kc, vc, cl)
+
+    # the chunk's routing alone: a threshold no row reaches
+    never = dataclasses.replace(RETRO, local=10**6)
+    routed = prefill_append_chunk(cp, kc, vc, never, cl)
+    assert int(routed.staged[0]) >= seg + RETRO.local
+    assert int(routed.staged[1]) < seg + RETRO.local
+
+    p0 = jnp.arange(seg, dtype=jnp.int32) + int(routed.seen[0]
+                                                - routed.staged[0])
+    want = jax.vmap(lambda k1, v1: cluster_segment(
+        k1[:seg], v1[:seg], p0, RETRO.avg_cluster, RETRO.cluster_cap,
+        RETRO.kmeans_iters, RETRO.centering))(routed.stage_k[0],
+                                              routed.stage_v[0])
+    for f, w in zip(want._fields, want):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(out.state, f)[0, :, :k_new]), np.asarray(w),
+            err_msg=f)
+    assert int(out.state.n_clusters[0]) == k_new
+    assert int(out.staged[0]) == int(routed.staged[0]) - seg
+    for a, b in ((out.stage_k, routed.stage_k), (out.stage_v, routed.stage_v)):
+        np.testing.assert_array_equal(np.asarray(a[0]),
+                                      np.roll(np.asarray(b[0]), -seg, axis=1))
+
+    leaves = jax.tree_util.tree_flatten_with_path(out)[0]
+    for (path, a), r in zip(leaves, jax.tree.leaves(routed)):
+        np.testing.assert_array_equal(np.asarray(a)[1], np.asarray(r)[1],
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+def test_stage_flush_is_gated_by_cond():
+    """The staging flush sits behind a ``cond``: a refactor that drops the
+    gate clusters a segment on every chunk again."""
+    cp = init_chunked_prefill(1, 1, 16, 256, RETRO, 64, dtype=jnp.float32)
+    k = jnp.zeros((1, 64, 1, 16), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.jit(
+        lambda cp, k: prefill_append_chunk(cp, k, k, RETRO)))(cp, k)
+    assert " cond[" in str(jaxpr)
+
+
+@pytest.mark.parametrize("chunk", (96, 256, 300, 1100))
+def test_stage_flushes_counts_device_flushes(chunk):
+    """The host count of staging flushes per chunk equals the segments the
+    device clustered: each flush lowers ``staged`` by one segment."""
+    _, k, v = _build()
+    B, n, H, hd = k.shape
+    M = max_clusters(n, RETRO, gen_headroom=128)
+    cp = init_chunked_prefill(B, H, hd, M, RETRO, chunk, dtype=jnp.float32)
+    app = jax.jit(lambda cp, kc, vc, cl: prefill_append_chunk(
+        cp, kc, vc, RETRO, cl))
+    total = 0
+    for t in range(0, n, chunk):
+        c = min(chunk, n - t)
+        kc = jnp.zeros((B, chunk, H, hd)).at[:, :c].set(k[:, t:t + c])
+        vc = jnp.zeros((B, chunk, H, hd)).at[:, :c].set(v[:, t:t + c])
+        before = int(cp.staged[0])
+        cp = app(cp, kc, vc, jnp.full((B,), c, jnp.int32))
+        added = c - min(max(RETRO.sink - t, 0), c)
+        dropped = before + added - int(cp.staged[0])
+        assert dropped % RETRO.prefill_segment == 0
+        assert stage_flushes(t, c, RETRO) == dropped // RETRO.prefill_segment
+        total += dropped // RETRO.prefill_segment
+    assert total == prefill_layout(n, RETRO)[0]
 
 
 @pytest.mark.slow
