@@ -77,16 +77,6 @@ def wave_attention_call(cfgd: dict, batch: int, max_context: int):
     return per_row_flops * rows, per_row_bytes * rows
 
 
-def decode_matmul_flops(cfgd: dict) -> int:
-    """Matmul operations of one slot's decode step: 2 per parameter of the
-    q/k/v/o projections, the gated MLP and the LM head; attention itself
-    excluded."""
-    d, a = cfgd["d_model"], cfgd["attn"]
-    hq, hkv = a["n_heads"] * a["head_dim"], a["n_kv_heads"] * a["head_dim"]
-    per_layer = d * (hq + 2 * hkv) + hq * d + 3 * d * cfgd["d_ff"]
-    return 2 * (cfgd["n_layers"] * per_layer + d * cfgd["vocab"])
-
-
 def roofline_seconds(flops: float, nbytes: float, peak: dict):
     """(least seconds, bound) of work on a chip with ``peak``."""
     t_c = flops / peak["bf16_flops_per_s"]

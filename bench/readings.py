@@ -50,7 +50,7 @@ def _engine(cfgd, traffic, hook=None):
 def _read(engine, cfgd, traffic, seed, n, controls, label, limits):
     from bench import weights
     if engine.params is None:
-        engine.params = weights.make_params(cfgd, seed)
+        engine.params = spec.load_block(cfgd).make_params(cfgd, seed)
     pool = weights.make_patch_pool(cfgd, seed, traffic.get("patch_pool", 0))
     specs = gen.request_stream(traffic, cfgd["vocab"], seed, n)
     reqs = harness._requests(specs, pool)

@@ -11,7 +11,8 @@ threads are lines of the plane ``/host:CPU``, on the same clock.
 
 Only events inside the traced window count: the host span named
 ``bench.window``, which the harness holds over the traced part of its call
-into the engine.
+into the engine. ``reduce_file`` adds the device time by named scope and
+the idle time behind the serve loop's readbacks (``bench/scopes.py``).
 """
 from __future__ import annotations
 
@@ -20,6 +21,7 @@ import gzip
 import heapq
 import re
 from collections import defaultdict
+from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
 WINDOW_SPAN = "bench.window"
@@ -164,10 +166,24 @@ def _attribute_gaps(gaps, hosts, lo, hi) -> Dict[str, float]:
     return out
 
 
+def read_raw(path) -> bytes:
+    """The serialized XSpace of an ``.xplane.pb`` file (or a gzipped one,
+    ``.gz``)."""
+    raw = Path(path).read_bytes()
+    return gzip.decompress(raw) if str(path).endswith(".gz") else raw
+
+
 def reduce_file(path) -> Dict:
-    """Reduce an ``.xplane.pb`` file (or a gzipped one, ``.gz``)."""
+    """Reduce an ``.xplane.pb`` file (or a gzipped one, ``.gz``):
+    ``reduce_profile``'s keys, and ``scopes`` ({program: {scope: [leaf
+    operations, device seconds]}}) and ``sync_idle_s`` of
+    ``bench.scopes.reduce_profile``."""
     from jax.profiler import ProfileData
-    if str(path).endswith(".gz"):
-        with gzip.open(path, "rb") as f:
-            return reduce_profile(ProfileData.from_serialized_xspace(f.read()))
-    return reduce_profile(ProfileData.from_file(str(path)))
+
+    from bench import scopes
+    raw = read_raw(path)
+    pd = ProfileData.from_serialized_xspace(raw)
+    out = reduce_profile(pd)
+    by_scope = scopes.reduce_profile(pd, scopes.op_paths(raw))
+    out.update(scopes=by_scope["scopes"], sync_idle_s=by_scope["sync_idle_s"])
+    return out

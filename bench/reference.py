@@ -1,14 +1,16 @@
 """Plain float32 reference of the served model, and the comparison that
 decides ``correct``.
 
-The reference imports nothing of the program. It makes the weights again
-from the seed (``bench.weights``), one layer at a time, and runs the
-architecture's equations in float32 with every matmul at ``HIGHEST``
-precision: exact causal softmax attention over every earlier position (no
-wave index, no cache), RMSNorm with a ``(1 + gamma)`` scale, half-split
-rotary embeddings, grouped-query heads, a SiLU-gated MLP and an untied
-head. Image-patch embeddings replace the token embeddings of the first
-``num_patch_tokens`` positions.
+The reference imports nothing of the program. Its layers are the
+configuration's block (``bench/blocks/<block>.py``, named by the file's
+``block`` key): the block's ``final_hidden`` makes the weights again from
+the seed, one layer at a time, and runs the architecture's equations in
+float32 with every matmul at ``HIGHEST`` precision, with exact attention
+(no wave index, no cache). This module holds what every block shares:
+the linear layer and its control rounding, RMSNorm with a ``(1 + gamma)``
+scale, half-split rotary embeddings, exact causal grouped-query
+attention, the head and the comparison. Image-patch embeddings replace
+the token embeddings of the first ``num_patch_tokens`` positions.
 
 For one served request (prompt, served tokens) the forward pass runs over
 the prompt and every served token but the last; the row at position
@@ -26,14 +28,15 @@ each position.
 """
 from __future__ import annotations
 
-from functools import lru_cache, partial
-from typing import List, Optional
+from functools import partial
+from typing import List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import layer_weights, seed_key, top_weight
+from bench import spec
+from bench.weights import seed_key, top_weight
 
 HI = jax.lax.Precision.HIGHEST
 PAD = 1024          # positions are padded to a multiple (fewer compiles)
@@ -103,75 +106,6 @@ def _attention(q, k, v):
     return jax.lax.map(block, jnp.arange(T // Q_BLOCK)).reshape(T, Hq * hd)
 
 
-@partial(jax.jit, static_argnames=("cfgd_items", "quant"))
-def _layer(w, x, cfgd_items, quant):
-    c = _unfreeze(cfgd_items)
-    a = c["attn"]
-    T = x.shape[0]
-    eps = c["norm_eps"]
-    pos = jnp.arange(T)
-    h = _rms_norm(x, w["ln1"], eps)
-    q = _linear(h, w["wq"], quant).reshape(T, a["n_heads"], a["head_dim"])
-    k = _linear(h, w["wk"], quant).reshape(T, a["n_kv_heads"], a["head_dim"])
-    v = _linear(h, w["wv"], quant).reshape(T, a["n_kv_heads"], a["head_dim"])
-    q, k = _rope(q, pos, a["rope_theta"]), _rope(k, pos, a["rope_theta"])
-    x = x + _linear(_attention(q, k, v), w["wo"], quant)
-
-    def mlp(xb):
-        hb = _rms_norm(xb, w["ln2"], eps)
-        g = jax.nn.silu(_linear(hb, w["w_gate"], quant))
-        return xb + _linear(g * _linear(hb, w["w_up"], quant), w["w_down"],
-                            quant)
-
-    rows = x.reshape(T // ROW_BLOCK, ROW_BLOCK, -1)
-    return jax.lax.map(mlp, rows).reshape(x.shape)
-
-
-def _frozen(cfgd):
-    """Hashable view of the sizes the reference needs (a jit static)."""
-    keep = ("d_model", "d_ff", "vocab", "norm_eps", "n_layers", "dtype")
-    attn = tuple(sorted((k, v) for k, v in cfgd["attn"].items()
-                        if k in ("n_heads", "n_kv_heads", "head_dim",
-                                 "rope_theta")))
-    return tuple(sorted([(k, cfgd[k]) for k in keep] + [("attn", attn)]))
-
-
-def _unfreeze(frozen) -> dict:
-    c = dict(frozen)
-    c["attn"] = dict(c["attn"])
-    return c
-
-
-@lru_cache(maxsize=None)
-def _layer_maker(frozen):
-    cfgd = _unfreeze(frozen)
-    return jax.jit(lambda key, layer: layer_weights(cfgd, key, layer))
-
-
-def final_hidden(cfgd, seed: int, tokens: np.ndarray, rows: slice,
-                 patches=None, quant: Optional[str] = None):
-    """Final-norm hidden states (n, d) f32 at positions ``rows`` of a
-    forward pass over ``tokens``. ``patches``: (1, P, d) image-patch
-    embeddings for the first P positions, or None."""
-    key = seed_key(seed)
-    T = len(tokens)
-    Tp = -(-T // PAD) * PAD
-    toks = np.zeros(Tp, np.int32)
-    toks[:T] = tokens
-    embed = top_weight(cfgd, key, "embed")
-    x = embed[jnp.asarray(toks)].astype(jnp.float32) * np.sqrt(cfgd["d_model"])
-    del embed
-    if patches is not None:
-        P = patches.shape[1]
-        x = x.at[:P].set(patches[0].astype(jnp.float32))
-    frozen = _frozen(cfgd)
-    make_layer = _layer_maker(frozen)
-    for layer in range(cfgd["n_layers"]):
-        x = _layer(make_layer(key, layer), x, frozen, quant)
-    gamma = top_weight(cfgd, key, "final_norm")
-    return _rms_norm(x[rows], gamma, cfgd["norm_eps"])
-
-
 def _head(cfgd, key):
     name = "embed" if cfgd["tie_embeddings"] else "lm_head"
     w = top_weight(cfgd, key, name)
@@ -206,7 +140,7 @@ def served_gaps(cfgd, seed: int, prompt: np.ndarray, served: List[int],
     tokens = np.concatenate([prompt, served[:-1]]).astype(np.int32)
     rows = slice(len(prompt) - 1, len(prompt) - 1 + len(served))
     key = seed_key(seed)
-    h = final_hidden(cfgd, seed, tokens, rows, patches)
+    h = spec.load_block(cfgd).final_hidden(cfgd, seed, tokens, rows, patches)
     w = _head(cfgd, key)
     mx, at, _ = _head_rows(h, w, jnp.asarray(served), None)
     gaps = np.asarray(mx - at, np.float64)
@@ -225,7 +159,8 @@ def control_choices(cfgd, seed: int, prompt: np.ndarray, served: List[int],
     served = np.asarray(served, np.int32)
     tokens = np.concatenate([prompt, served[:-1]]).astype(np.int32)
     rows = slice(len(prompt) - 1, len(prompt) - 1 + len(served))
-    hq = final_hidden(cfgd, seed, tokens, rows, patches, quant=quant)
+    hq = spec.load_block(cfgd).final_hidden(cfgd, seed, tokens, rows, patches,
+                                            quant=quant)
     _, _, c_tok = _head_rows(hq, _head(cfgd, seed_key(seed)),
                              jnp.asarray(served), quant)
     return np.asarray(c_tok)
@@ -236,6 +171,7 @@ def control_decode(cfgd, seed: int, prompt: np.ndarray, n: int,
     """``n`` tokens decoded greedily by the ``quant`` control after
     ``prompt``, each step a whole forward pass (small sizes only)."""
     w = _head(cfgd, seed_key(seed))
+    final_hidden = spec.load_block(cfgd).final_hidden
     tokens = np.asarray(prompt, np.int32)
     out = []
     for _ in range(n):

@@ -7,7 +7,8 @@ A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a configuration
 (``bench/traffic/<traffic>.json``). One run is one process:
 
 1. set-up (``setup_s``, from process start): weights made on the device
-   from ``--seed`` in one jitted call; one ``ServeEngine``; warm-up queues
+   from ``--seed`` in one jitted call by the configuration's block
+   (``bench/blocks/<block>.py``); one ``ServeEngine``; warm-up queues
    served through it that compile every program the window can run;
 2. the window: one ``ServeEngine.serve`` call over the first N requests of
    the seeded stream, N fixed by the traffic file's ``blocks`` (fixed work,
@@ -18,9 +19,10 @@ A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a configuration
 3. the peak device memory is read and the program's state freed;
 4. ``correct``: a sample of the finished requests (drawn from the seed,
    the longest among them) is run through the plain float32 reference
-   (``bench/reference.py``); the widest gap by which a served token's
-   logit lies below the reference's best, and the mean gap over the
-   sample, are held to the cell's limits (``bench/cells/<cell>.json``).
+   (``bench/reference.py`` over the block's layers); the widest gap by
+   which a served token's logit lies below the reference's best, and the
+   mean gap over the sample, are held to the cell's limits
+   (``bench/cells/<cell>.json``).
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
@@ -281,10 +283,11 @@ def run_cell(cell: dict, cfgd: dict, traffic: dict, limits: dict,
     from bench import flops, weights
 
     cfg = spec.model_config(cfgd)
+    block = spec.load_block(cfgd)
     slots = traffic["engine"]["slots"]
     counter = CompileCounter()
 
-    params = weights.make_params(cfgd, seed)
+    params = block.make_params(cfgd, seed)
     _check_params(cfg, params)
     pool = weights.make_patch_pool(cfgd, seed, traffic.get("patch_pool", 0))
     engine = make_engine(cfg, traffic, params)
@@ -345,7 +348,8 @@ def run_cell(cell: dict, cfgd: dict, traffic: dict, limits: dict,
     run = SimpleNamespace(cell=cell, cfgd=cfgd, traffic=traffic,
                           serve=serve_metrics, requests=reqs, wall_s=wall_s,
                           setup_s=setup_s, trace=reduced, device=device,
-                          peaks=flops.peaks(device["kind"]), flops=flops)
+                          peaks=flops.peaks(device["kind"]), flops=flops,
+                          block=block)
     out_metrics = {}
     for m in metrics:
         value = spec.load_reader(m["name"])(run)

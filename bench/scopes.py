@@ -34,7 +34,6 @@ Times are clipped to the host span ``bench.window``, as in
 from __future__ import annotations
 
 import bisect
-import gzip
 import heapq
 import json
 import re
@@ -244,9 +243,7 @@ def reduce_profile(pd, paths: Dict[Tuple[int, str], str]) -> Dict:
 def reduce_file(path) -> Dict:
     """Reduce an ``.xplane.pb`` file (or a gzipped one, ``.gz``)."""
     from jax.profiler import ProfileData
-    raw = Path(path).read_bytes()
-    if str(path).endswith(".gz"):
-        raw = gzip.decompress(raw)
+    raw = rt.read_raw(path)
     return reduce_profile(ProfileData.from_serialized_xspace(raw),
                           op_paths(raw))
 

@@ -42,6 +42,9 @@ def test_config_file_loads_and_builds(entry):
     assert set(entry["reduced"]) == set(cfgd["reduced"])
     for key in cfgd["reduced"]:
         assert key in cfgd and cfgd[key] != cfgd["published"][key]
+    block = spec.load_block(cfgd)
+    assert all(callable(getattr(block, f)) for f in spec.BLOCK_FUNCTIONS)
+    assert block.decode_matmul_flops(cfgd) > 0
     cfg = spec.model_config(cfgd)
     assert (cfg.n_layers, cfg.d_model, cfg.vocab) == (
         cfgd["n_layers"], cfgd["d_model"], cfgd["vocab"])

@@ -34,12 +34,6 @@ def test_wave_attention_call_by_hand():
     assert fl == 6 * per_row_flops
 
 
-def test_decode_matmul_flops_by_hand():
-    # per layer: q 8x16, k 8x8, v 8x8, o 16x8, MLP 3 x 8x16; head 8x10
-    per_layer = 8 * 16 + 8 * 8 + 8 * 8 + 16 * 8 + 3 * 8 * 16
-    assert flops.decode_matmul_flops(CFG) == 2 * (2 * per_layer + 8 * 10)
-
-
 def test_roofline_names_its_bound():
     peak = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
     assert flops.roofline_seconds(50.0, 100.0, peak) == (10.0, "memory")

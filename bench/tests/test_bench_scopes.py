@@ -1,7 +1,8 @@
 """``bench/scopes.py``: device time by named scope and device idle behind the
 serve loop's readbacks, by hand on a made-up trace, on hand-encoded XSpace
-bytes, and on the small trace recorded on a TPU v5e; and the reader of
-``host_wait_share``."""
+bytes, and on the small trace recorded on a TPU v5e; the same numbers in
+``reduce_trace.reduce_file``'s reduction, and the metric readers of them and
+of ``host_wait_share``."""
 from pathlib import Path
 from types import SimpleNamespace as NS
 
@@ -216,3 +217,53 @@ def test_host_wait_share_reader(serve, value):
     read = spec.load_reader("host_wait_share")
     got = read(NS(serve=serve, wall_s=5.0, trace=None))
     assert got == (value if value is None else pytest.approx(value))
+
+
+SCOPE_READERS = ("decode_loop_ms", "decode_rank_ms", "chunk_attend_ms",
+                 "chunk_index_ms", "sync_idle_share")
+
+
+def _read_all(trace):
+    return {m: spec.load_reader(m)(NS(trace=trace)) for m in SCOPE_READERS}
+
+
+def test_reduce_file_carries_scopes_and_readback_idle(tmp_path):
+    """``reduce_trace.reduce_file`` adds ``scopes`` and ``sync_idle_s`` to
+    its reduction, and the scope readers take per-call times from them:
+    ``None`` for a scope the programs do not hold (``attend`` in
+    ``chunk``)."""
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace())
+    red = rt.reduce_file(path)
+    by_scope = scopes.reduce_file(path)
+    assert red["scopes"] == by_scope["scopes"]
+    assert red["sync_idle_s"] == by_scope["sync_idle_s"]
+    assert red["modules"] == rt.reduce_profile(_profile()[0])["modules"]
+    assert _read_all(red) == {
+        "decode_loop_ms": pytest.approx(15e-3),
+        "decode_rank_ms": pytest.approx(10e-3),
+        "chunk_attend_ms": None,
+        "chunk_index_ms": pytest.approx(30e-3),
+        "sync_idle_share": pytest.approx(20.0)}
+
+
+def test_scope_readers_on_the_recorded_trace():
+    """The recorded v5e trace predates the scopes: every operation is the
+    loop's, so only ``decode_loop_ms`` and the readback idle (none) read."""
+    red = rt.reduce_file(DATA)
+    for key in ("modules", "ops", "busy_s", "window_s", "chips"):
+        assert key in red
+    assert set(red["scopes"]) >= {"decode", "chunk", "fin", "flush"}
+    got = _read_all(red)
+    calls, secs = red["modules"]["decode"]
+    assert 0 < got["decode_loop_ms"] <= 1e3 * secs / calls + 1e-9
+    assert got["sync_idle_share"] == 0.0
+    assert got["decode_rank_ms"] is got["chunk_attend_ms"] is None
+    assert got["chunk_index_ms"] is None
+
+
+@pytest.mark.parametrize("trace", [
+    None, {"modules": {"decode": [3, 0.03], "chunk": [2, 0.02]},
+           "window_s": 1.0}], ids=["untraced", "no-scope-keys"])
+def test_scope_readers_read_nothing_without_their_keys(trace):
+    assert _read_all(trace) == dict.fromkeys(SCOPE_READERS)
